@@ -168,6 +168,10 @@ def slo_pair(num_requests, max_new, slots, k, inter, trials=5):
         "ensemble": k,
         "mean_interarrival": inter,
         "variant": "refresh_slo",
+        # this row runs in a forced-CPU-device child: its own platform, not
+        # the parent's, is what its times were taken on
+        "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "refresh_every": 48,
         "sampler_chunk_steps": 2,
         "trials": trials,

@@ -6,9 +6,10 @@ Each device count runs in its OWN subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must be
 set before jax picks a backend, and this parent has usually already locked
 one) — ``repro.launch.mesh.forced_device_env`` builds the environment, the
-same fallback the multidevice test harness uses.  On a real multi-device
-install the forced flag is inert surplus and the children see the actual
-accelerators.
+same fallback the multidevice test harness uses.  That environment pins
+``JAX_PLATFORMS=cpu``, so the children run on forced CPU devices even on an
+accelerator host; every row records the backend and device kind its child
+actually ran on.
 
 Recorded per (device count, mode): steps/s of the compiled sharded program
 and the per-device sync wire payload of one s-periodic center exchange
@@ -66,8 +67,9 @@ _CHILD = textwrap.dedent(
         res = ex.run_sharded(params0 + 0.0, sampler.init(params0), num_steps=steps,
                              key=jax.random.key(0), mesh=mesh)
         ok = bool(np.all(np.isfinite(np.asarray(res.params))))
+        kind = jax.devices()[0].device_kind.replace(" ", "_")
         print(f"RESULT devices={n} mode={mode} steps_per_s={res.steps_per_s:.2f} "
-              f"ok={ok}", flush=True)
+              f"ok={ok} backend={jax.default_backend()} device_kind={kind}", flush=True)
     """
 )
 
@@ -107,6 +109,8 @@ def run():
                 {
                     "devices": n,
                     "mode": mode,
+                    "backend": kv["backend"],
+                    "device_kind": kv["device_kind"],
                     "steps_per_s": round(sps, 2),
                     "sync_wire_bytes_per_device": wire,
                     "syncs_per_run": STEPS // SYNC,

@@ -12,9 +12,10 @@ Gaussian noise tensor in HBM, and round-trips p twice — ~9 tensor streams
 vs. our 6.5 (the roofline win for the memory-bound sampler sweep).
 
 Gaussian noise is derived in-register from uint32 bits via Box-Muller.
-On real TPU the bits come from pltpu.prng_random_bits (no HBM traffic at
-all); the CPU-interpret validation path takes bits as an input so the
-pure-jnp oracle sees identical randomness.  bf16 parameter stores use
+On real TPU the bits come from pltpu.prng_random_bits, seeded from the
+caller's key and the block index (no HBM noise traffic at all); the
+CPU-interpret validation path takes bits as an input so the pure-jnp oracle
+sees identical randomness.  bf16 parameter stores use
 STOCHASTIC ROUNDING (bits reused) — plain round-to-nearest bf16 MCMC biases
 the stationary distribution at 1e-5-scale step sizes.
 """
@@ -32,8 +33,10 @@ BLOCK_ROWS = 8  # rows of LANES per grid step
 
 
 def _bits_to_unit(bits):
-    """uint32 -> uniform (0, 1) f32 using the top 24 bits."""
-    return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) + (0.5 / (1 << 24))
+    """uint32 -> uniform (0, 1) f32 using the top 24 bits.  ``bits >> 8``
+    fits in int32, and Mosaic converts int32 (not uint32) to f32."""
+    top = (bits >> 8).astype(jnp.int32)
+    return top.astype(jnp.float32) * (1.0 / (1 << 24)) + (0.5 / (1 << 24))
 
 
 def _box_muller(bits1, bits2):
@@ -51,20 +54,32 @@ def _stochastic_round_bf16(x_f32, bits):
     return jax.lax.bitcast_convert_type(xi, jnp.float32).astype(jnp.bfloat16)
 
 
-def _kernel(
-    scal_ref,  # SMEM (5,): eps_minv, decay, eps, coupling, sigma_p
-    theta_ref,
-    p_ref,
-    g_ref,
-    c_ref,
-    bits1_ref,
-    bits2_ref,
-    theta_out_ref,
-    p_out_ref,
-    *,
-    stochastic_round: bool,
-    onchip_prng: bool,
-):
+def _noise_bits(noise_refs, shape):
+    """Two uint32 bit blocks: drawn on chip from the SMEM seed mixed with the
+    block index (Mosaic takes at most two seed words), or read from the two
+    streamed bit inputs."""
+    if len(noise_refs) == 1:
+        pltpu.prng_seed(noise_refs[0][0], pl.program_id(0))
+        draw = lambda: jax.lax.bitcast_convert_type(pltpu.prng_random_bits(shape), jnp.uint32)
+        return draw(), draw()
+    return noise_refs[0][...], noise_refs[1][...]
+
+
+def _store(theta_out_ref, p_out_ref, theta_new, p_new, bits1, bits2, stochastic_round):
+    if stochastic_round and theta_out_ref.dtype == jnp.bfloat16:
+        sr_bits = bits1 ^ bits2
+        theta_out_ref[...] = _stochastic_round_bf16(theta_new, sr_bits)
+        p_out_ref[...] = _stochastic_round_bf16(p_new, jnp.uint32(0x9E3779B9) ^ sr_bits)
+    else:
+        theta_out_ref[...] = theta_new.astype(theta_out_ref.dtype)
+        p_out_ref[...] = p_new.astype(p_out_ref.dtype)
+
+
+def _kernel(scal_ref, theta_ref, p_ref, g_ref, c_ref, *refs, stochastic_round: bool):
+    """``scal_ref`` SMEM (5,): eps_minv, decay, eps, coupling, sigma_p.
+    ``refs``: the noise input(s) — an SMEM int32 seed (1,) or two uint32 bit
+    blocks — then theta', p'."""
+    *noise_refs, theta_out_ref, p_out_ref = refs
     eps_minv = scal_ref[0]
     decay = scal_ref[1]
     eps = scal_ref[2]
@@ -75,25 +90,46 @@ def _kernel(
     p = p_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
-    if onchip_prng:  # TPU target: zero-HBM-traffic noise
-        pltpu.prng_seed(pl.program_id(0))
-        bits1 = pltpu.prng_random_bits(theta.shape).astype(jnp.uint32)
-        bits2 = pltpu.prng_random_bits(theta.shape).astype(jnp.uint32)
-    else:
-        bits1 = bits1_ref[...]
-        bits2 = bits2_ref[...]
+    bits1, bits2 = _noise_bits(noise_refs, theta.shape)
 
     noise = _box_muller(bits1, bits2)
     theta_new = theta + eps_minv * p
     p_new = decay * p - eps * g - coupling * (theta - c) + sigma_p * noise
+    _store(theta_out_ref, p_out_ref, theta_new, p_new, bits1, bits2, stochastic_round)
 
-    if stochastic_round and theta_out_ref.dtype == jnp.bfloat16:
-        sr_bits = bits1 ^ bits2
-        theta_out_ref[...] = _stochastic_round_bf16(theta_new, sr_bits)
-        p_out_ref[...] = _stochastic_round_bf16(p_new, jnp.uint32(0x9E3779B9) ^ sr_bits)
-    else:
-        theta_out_ref[...] = theta_new.astype(theta_out_ref.dtype)
-        p_out_ref[...] = p_new.astype(p_out_ref.dtype)
+
+def _block():
+    return pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
+
+
+def _noise_operands(bits1, bits2, seed):
+    """(in_specs, operands) of the noise input: the on-chip seed in SMEM, or
+    the two streamed bit tensors.  Exactly one of the two must be given."""
+    if (seed is None) == (bits1 is None or bits2 is None):
+        raise ValueError("pass either seed (on-chip PRNG) or both bits1 and bits2")
+    if seed is not None:
+        return [pl.BlockSpec(memory_space=pltpu.SMEM)], [seed.astype(jnp.int32)]
+    return [_block(), _block()], [bits1, bits2]
+
+
+def _call(kernel, scalars, tensors, bits1, bits2, seed, *, stochastic_round, interpret):
+    theta, p = tensors[0], tensors[1]
+    R, L = theta.shape
+    assert L == LANES and R % BLOCK_ROWS == 0, (theta.shape,)
+    noise_specs, noise = _noise_operands(bits1, bits2, seed)
+    return pl.pallas_call(
+        functools.partial(kernel, stochastic_round=stochastic_round),
+        grid=(R // BLOCK_ROWS,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [_block() for _ in tensors]
+        + noise_specs,
+        out_specs=(_block(), _block()),
+        out_shape=(
+            jax.ShapeDtypeStruct(theta.shape, theta.dtype),
+            jax.ShapeDtypeStruct(p.shape, p.dtype),
+        ),
+        interpret=interpret,
+    )(scalars, *tensors, *noise)
 
 
 def fused_ec_update_flat(
@@ -101,22 +137,22 @@ def fused_ec_update_flat(
     p,
     g,
     c_tilde,
-    bits1,
-    bits2,
+    bits1=None,
+    bits2=None,
     *,
+    seed=None,
     eps: float,
     friction: float,
     mass: float,
     alpha: float,
     sigma_p: float,
     stochastic_round: bool = True,
-    onchip_prng: bool = False,
     interpret: bool = True,
 ):
     """Core entry: all operands (R, LANES)-shaped, R % BLOCK_ROWS == 0.
-    Hyperparameters may be traced (they travel via SMEM)."""
-    R, L = theta.shape
-    assert L == LANES and R % BLOCK_ROWS == 0, (theta.shape,)
+    Hyperparameters may be traced (they travel via SMEM).  Noise comes from
+    the uint32 tensors ``bits1``/``bits2``, or, with ``seed`` (int32 (1,)),
+    from the TPU's on-chip PRNG seeded by it and the block index."""
     minv = 1.0 / mass
     scalars = jnp.stack(
         [
@@ -127,46 +163,14 @@ def fused_ec_update_flat(
             jnp.asarray(sigma_p, jnp.float32),
         ]
     )
-    grid = (R // BLOCK_ROWS,)
-    blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    kernel = functools.partial(
-        _kernel, stochastic_round=stochastic_round, onchip_prng=onchip_prng
+    return _call(
+        _kernel, scalars, (theta, p, g, c_tilde), bits1, bits2, seed,
+        stochastic_round=stochastic_round, interpret=interpret,
     )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-        ],
-        out_specs=(blk(), blk()),
-        out_shape=(
-            jax.ShapeDtypeStruct(theta.shape, theta.dtype),
-            jax.ShapeDtypeStruct(p.shape, p.dtype),
-        ),
-        interpret=interpret,
-    )(scalars, theta, p, g, c_tilde, bits1, bits2)
 
 
 def _precond_kernel(
-    scal_ref,  # SMEM (4,): eps, ef (= eps*V), coupling (= eps*alpha), sigma_p
-    theta_ref,
-    p_ref,
-    g_ref,
-    c_ref,
-    minv_ref,  # per-element M^-1 block (frozen diagonal preconditioner)
-    bits1_ref,
-    bits2_ref,
-    theta_out_ref,
-    p_out_ref,
-    *,
-    stochastic_round: bool,
-    onchip_prng: bool,
+    scal_ref, theta_ref, p_ref, g_ref, c_ref, minv_ref, *refs, stochastic_round: bool
 ):
     """Preconditioned Eq. 6 chain update — ``_kernel`` with the scalar
     eps*M^-1 / decay pair replaced by a streamed diagonal M^-1:
@@ -174,10 +178,15 @@ def _precond_kernel(
         theta' = theta + (eps*M^-1) * p
         p'     = (1 - ef*M^-1)*p - eps*g - coupling*(theta - c̃) + sigma_p*n
 
+    ``scal_ref`` SMEM (4,): eps, ef (= eps*V), coupling (= eps*alpha),
+    sigma_p; ``minv_ref`` the per-element M^-1 block (frozen diagonal
+    preconditioner); ``refs`` as in ``_kernel``.
+
     Term grouping mirrors ``core.ec_sghmc.p_step`` with an ARRAY ``minv``
     (ef*minv, then 1 - ·), so fused and unfused agree bit-for-bit in f32 —
     pinned by tests/test_fused_equivalence.py.  One extra HBM read stream
     (M^-1) vs. the plain kernel; still beats XLA's ~10 streams."""
+    *noise_refs, theta_out_ref, p_out_ref = refs
     eps = scal_ref[0]
     ef = scal_ref[1]
     coupling = scal_ref[2]
@@ -188,25 +197,12 @@ def _precond_kernel(
     g = g_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
     minv = minv_ref[...].astype(jnp.float32)
-    if onchip_prng:  # TPU target: zero-HBM-traffic noise
-        pltpu.prng_seed(pl.program_id(0))
-        bits1 = pltpu.prng_random_bits(theta.shape).astype(jnp.uint32)
-        bits2 = pltpu.prng_random_bits(theta.shape).astype(jnp.uint32)
-    else:
-        bits1 = bits1_ref[...]
-        bits2 = bits2_ref[...]
+    bits1, bits2 = _noise_bits(noise_refs, theta.shape)
 
     noise = _box_muller(bits1, bits2)
     theta_new = theta + eps * minv * p
     p_new = (1.0 - ef * minv) * p - eps * g - coupling * (theta - c) + sigma_p * noise
-
-    if stochastic_round and theta_out_ref.dtype == jnp.bfloat16:
-        sr_bits = bits1 ^ bits2
-        theta_out_ref[...] = _stochastic_round_bf16(theta_new, sr_bits)
-        p_out_ref[...] = _stochastic_round_bf16(p_new, jnp.uint32(0x9E3779B9) ^ sr_bits)
-    else:
-        theta_out_ref[...] = theta_new.astype(theta_out_ref.dtype)
-        p_out_ref[...] = p_new.astype(p_out_ref.dtype)
+    _store(theta_out_ref, p_out_ref, theta_new, p_new, bits1, bits2, stochastic_round)
 
 
 def fused_precond_ec_update_flat(
@@ -215,22 +211,21 @@ def fused_precond_ec_update_flat(
     g,
     c_tilde,
     minv,
-    bits1,
-    bits2,
+    bits1=None,
+    bits2=None,
     *,
+    seed=None,
     eps: float,
     friction: float,
     alpha: float,
     sigma_p: float,
     stochastic_round: bool = True,
-    onchip_prng: bool = False,
     interpret: bool = True,
 ):
     """Preconditioned entry: operands (R, LANES)-shaped, R % BLOCK_ROWS == 0,
     ``minv`` elementwise (the frozen diagonal M^-1).  Hyperparameters may be
-    traced (SMEM); the diagonal streams as a tensor block."""
-    R, L = theta.shape
-    assert L == LANES and R % BLOCK_ROWS == 0, (theta.shape,)
+    traced (SMEM); the diagonal streams as a tensor block.  Noise as in
+    :func:`fused_ec_update_flat`."""
     assert minv.shape == theta.shape, (minv.shape, theta.shape)
     scalars = jnp.stack(
         [
@@ -240,28 +235,7 @@ def fused_precond_ec_update_flat(
             jnp.asarray(sigma_p, jnp.float32),
         ]
     )
-    grid = (R // BLOCK_ROWS,)
-    blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    kernel = functools.partial(
-        _precond_kernel, stochastic_round=stochastic_round, onchip_prng=onchip_prng
+    return _call(
+        _precond_kernel, scalars, (theta, p, g, c_tilde, minv), bits1, bits2, seed,
+        stochastic_round=stochastic_round, interpret=interpret,
     )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-            blk(),
-        ],
-        out_specs=(blk(), blk()),
-        out_shape=(
-            jax.ShapeDtypeStruct(theta.shape, theta.dtype),
-            jax.ShapeDtypeStruct(p.shape, p.dtype),
-        ),
-        interpret=interpret,
-    )(scalars, theta, p, g, c_tilde, minv, bits1, bits2)

@@ -34,6 +34,21 @@ def _pad_flat(x):
     return flat.reshape(-1, _LANES), n
 
 
+def _noise_kwargs(key, shape):
+    """The kernel's noise input for ``key``.  On TPU: an int32 seed word
+    for the on-chip PRNG (the kernel mixes in the block index), so no noise
+    tensor touches HBM.  Elsewhere: two uint32 bit tensors, so the pure-jnp
+    reference sees identical randomness."""
+    if _on_tpu():
+        seed = jax.lax.bitcast_convert_type(jax.random.bits(key, (1,), jnp.uint32), jnp.int32)
+        return {"seed": seed}
+    k1, k2 = jax.random.split(key)
+    return {
+        "bits1": jax.random.bits(k1, shape, jnp.uint32),
+        "bits2": jax.random.bits(k2, shape, jnp.uint32),
+    }
+
+
 @functools.partial(jax.jit, static_argnames=("stochastic_round",))
 def fused_ec_update(
     theta, p, g, c_tilde, key,
@@ -41,24 +56,16 @@ def fused_ec_update(
 ):
     """Single-leaf fused Eq. 6 update. Returns (theta_new, p_new) in the
     input dtypes.  Noise bits: jax.random on CPU-validation path; on-chip
-    PRNG on TPU (zero HBM noise traffic)."""
+    PRNG seeded from ``key`` on TPU (zero HBM noise traffic)."""
     shape, dtype_t, dtype_p = theta.shape, theta.dtype, p.dtype
     t2, n = _pad_flat(theta)
     p2, _ = _pad_flat(p)
     g2, _ = _pad_flat(g.astype(jnp.float32))
     c2, _ = _pad_flat(jnp.broadcast_to(c_tilde, theta.shape))
-    onchip = _on_tpu()
-    if onchip:
-        bits1 = bits2 = jnp.zeros(t2.shape, jnp.uint32)  # unused on TPU
-    else:
-        k1, k2 = jax.random.split(key)
-        bits1 = jax.random.bits(k1, t2.shape, jnp.uint32)
-        bits2 = jax.random.bits(k2, t2.shape, jnp.uint32)
     t_new, p_new = _fe.fused_ec_update_flat(
-        t2, p2, g2, c2, bits1, bits2,
+        t2, p2, g2, c2, **_noise_kwargs(key, t2.shape),
         eps=eps, friction=friction, mass=mass, alpha=alpha, sigma_p=sigma_p,
-        stochastic_round=stochastic_round, onchip_prng=onchip,
-        interpret=not onchip,
+        stochastic_round=stochastic_round, interpret=not _on_tpu(),
     )
     t_new = t_new.reshape(-1)[:n].reshape(shape).astype(dtype_t)
     p_new = p_new.reshape(-1)[:n].reshape(shape).astype(dtype_p)
@@ -95,18 +102,10 @@ def fused_precond_ec_update(
     g2, _ = _pad_flat(g.astype(jnp.float32))
     c2, _ = _pad_flat(jnp.broadcast_to(c_tilde, theta.shape))
     m2, _ = _pad_flat(jnp.broadcast_to(minv, theta.shape).astype(jnp.float32))
-    onchip = _on_tpu()
-    if onchip:
-        bits1 = bits2 = jnp.zeros(t2.shape, jnp.uint32)  # unused on TPU
-    else:
-        k1, k2 = jax.random.split(key)
-        bits1 = jax.random.bits(k1, t2.shape, jnp.uint32)
-        bits2 = jax.random.bits(k2, t2.shape, jnp.uint32)
     t_new, p_new = _fe.fused_precond_ec_update_flat(
-        t2, p2, g2, c2, m2, bits1, bits2,
+        t2, p2, g2, c2, m2, **_noise_kwargs(key, t2.shape),
         eps=eps, friction=friction, alpha=alpha, sigma_p=sigma_p,
-        stochastic_round=stochastic_round, onchip_prng=onchip,
-        interpret=not onchip,
+        stochastic_round=stochastic_round, interpret=not _on_tpu(),
     )
     t_new = t_new.reshape(-1)[:n].reshape(shape).astype(dtype_t)
     p_new = p_new.reshape(-1)[:n].reshape(shape).astype(dtype_p)
@@ -191,10 +190,7 @@ def fused_bma_select(logits, key, *, mode="probs", temperature=0.0, top_k=0):
     caller's key so sampled tokens are bit-identical to
     ``jax.random.categorical(key, logp/T)`` on the unfused path."""
     K, S, V = logits.shape
-    if temperature > 0.0:
-        gumbel = jax.random.gumbel(key, (S, V), jnp.float32)
-    else:
-        gumbel = jnp.zeros((S, V), jnp.float32)
+    gumbel = jax.random.gumbel(key, (S, V), jnp.float32) if temperature > 0.0 else None
     return _bs.bma_select(
         logits, gumbel,
         mode=mode, temperature=temperature, top_k=top_k,

@@ -2,8 +2,9 @@
 against a block-paged KV cache (DESIGN.md §8).
 
 The cache is a flat pool of fixed-size pages ``(num_pages, block_size,
-Hkv, d)``; each sequence owns an int32 block-table row mapping its logical
-KV blocks to pool pages.  Both the table ``(B, M)`` and the inclusive
+Hkv, d)``, viewed as ``(num_pages, block_size, Hkv*d)`` so that one kv-head
+of one page is a ``(block_size, d)`` tile the TPU can DMA.  Each sequence
+owns an int32 block-table row mapping its logical KV blocks to pool pages.  Both the table ``(B, M)`` and the inclusive
 context positions ``(B,)`` ride in through
 ``pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2)`` so the k/v
 BlockSpec index maps can chase ``tab[b, j]`` — page indirection costs a
@@ -31,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, NEG_INF
+from .flash_attention import NEG_INF
 
 
 def _paged_kernel(
@@ -55,8 +56,8 @@ def _paged_kernel(
     @pl.when(relevant)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)  # (G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)  # (bs, d)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q * scale, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (G, bs)
@@ -98,7 +99,7 @@ def paged_attention(
     Returns (B, Hkv, G, d).
     """
     B, Hkv, G, d = q.shape
-    _, bs, _, _ = k_pages.shape
+    P, bs, _, _ = k_pages.shape
     M = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
@@ -112,9 +113,10 @@ def paged_attention(
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, h, j, tab, ctx: (b, h, 0, 0)),
             # the indirection: logical block j of sequence b lives at page
-            # tab[b, j] — resolved in the index map from the prefetched table
-            pl.BlockSpec((1, bs, 1, d), lambda b, h, j, tab, ctx: (tab[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda b, h, j, tab, ctx: (tab[b, j], 0, h, 0)),
+            # tab[b, j] — resolved in the index map from the prefetched table;
+            # kv-head h is lane block h of the (bs, Hkv*d) page view
+            pl.BlockSpec((1, bs, d), lambda b, h, j, tab, ctx: (tab[b, j], 0, h)),
+            pl.BlockSpec((1, bs, d), lambda b, h, j, tab, ctx: (tab[b, j], 0, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, j, tab, ctx: (b, h, 0, 0)),
         scratch_shapes=[
@@ -127,8 +129,11 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pages, v_pages)
+    )(
+        block_tables, context_lens, q,
+        k_pages.reshape(P, bs, Hkv * d), v_pages.reshape(P, bs, Hkv * d),
+    )

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro import core
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model, init_params
 from repro.serve.engine import (
     ChainRefresher,
@@ -60,12 +61,18 @@ def _prior_grad(center):
 
 
 def _bootstrap_ensemble(specs, key, num: int):
+    """Members = centre + offsets, the offsets sampled from N(0, PRIOR_SCALE^2 I).
+    Sampling the offset keeps the centre out of the compiled sampler: a
+    closed-over centre would be embedded in the program as a constant the
+    size of the model."""
     center = init_params(specs, key)
-    start = jax.tree.map(lambda x: x + 0.0, center)  # rollout donates its input
-    members, res = collect_ensemble(
-        core.sgld(step_size=_EPS), _prior_grad(center), start,
+    offsets, res = collect_ensemble(
+        core.sgld(step_size=_EPS),
+        lambda d: jax.tree.map(lambda x: _PREC * x, d),
+        jax.tree.map(jnp.zeros_like, center),
         num_samples=num, key=jax.random.fold_in(key, 1), thin=16,
     )
+    members = jax.tree.map(lambda c, d: c[None] + d, center, offsets)
     return members, res
 
 
@@ -198,6 +205,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     tracer, trace_path = obs.configure(args.trace)
+    log.info(f"compile cache: {enable_compile_cache()}")
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     model = get_model(cfg)
     if args.engine:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import tree_broadcast_axis0
 from repro.data import synthetic_token_stream
 from repro.data.pipeline import chain_batches
@@ -76,6 +77,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     tracer, trace_path = obs.configure(args.trace)
+    log.info(f"compile cache: {enable_compile_cache()}")
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     model = get_model(cfg)
     sampler = default_sampler(cfg, args.arch, args.chains, args.sync_every)

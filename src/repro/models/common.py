@@ -181,11 +181,7 @@ def active_params(cfg: ModelConfig) -> int:
 
     specs = registry.get_model(cfg).param_specs(cfg)
     total = 0
-    # jax.tree.flatten_with_path only exists in newer jax; the tree_util
-    # spelling works everywhere (cf. train/checkpoint.py)
-    for path, s in jax.tree_util.tree_flatten_with_path(
-        specs, is_leaf=lambda x: isinstance(x, ParamSpec)
-    )[0]:
+    for s in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, ParamSpec)):
         n = int(np.prod(s.shape))
         if "expert" in s.axes and cfg.moe_num_experts:
             n = n * cfg.moe_top_k // cfg.moe_num_experts

@@ -340,12 +340,18 @@ def embed(cfg: ModelConfig, p, tokens):
 
 
 def _logits_chunk(cfg: ModelConfig, p, x):
+    # f32 out of the dot itself: a compute-dtype result would be rounded
+    # after an accumulation whose order the compiler picks per program, so
+    # two programs sharing this model (the engine's fused and unfused
+    # selection) would disagree by an ulp of bf16 and break token equality
     cd = cfg.compute_dtype
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x.astype(cd), p["table"].astype(cd))
+        logits = jnp.einsum("bsd,vd->bsv", x.astype(cd), p["table"].astype(cd),
+                            preferred_element_type=jnp.float32)
     else:
-        logits = jnp.einsum("bsd,dv->bsv", x.astype(cd), p["unembed"].astype(cd))
-    return _softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+        logits = jnp.einsum("bsd,dv->bsv", x.astype(cd), p["unembed"].astype(cd),
+                            preferred_element_type=jnp.float32)
+    return _softcap(logits, cfg.final_logit_softcap)
 
 
 def chunked_xent(cfg: ModelConfig, p, x, labels, mask=None):

@@ -48,7 +48,7 @@ def test_fused_matches_p_step_bitwise(seed, hyper):
     def fused(theta, p, g, c, bits1, bits2):
         return fused_ec_update_flat(
             theta, p, g, c, bits1, bits2,
-            stochastic_round=False, onchip_prng=False, interpret=True, **hyper,
+            stochastic_round=False, interpret=True, **hyper,
         )
 
     @jax.jit
@@ -93,7 +93,7 @@ def test_fused_precond_matches_p_step_bitwise(seed, hyper):
     def fused(theta, p, g, c, minv, bits1, bits2):
         return fused_precond_ec_update_flat(
             theta, p, g, c, minv, bits1, bits2,
-            stochastic_round=False, onchip_prng=False, interpret=True, **hyper,
+            stochastic_round=False, interpret=True, **hyper,
         )
 
     @jax.jit

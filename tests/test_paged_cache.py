@@ -266,7 +266,9 @@ class TestAllocatorProperties:
                 mn = max_news[k % len(max_news)]
                 if slot is not None and a.can_admit(np.arange(plen), mn):
                     a.admit(slot, np.arange(plen, dtype=np.int32), mn)
-                    live[slot] = mn
+                    # admit emits the first token; max_new - 1 decode
+                    # writes follow, which is what the reservation covers
+                    live[slot] = mn - 1
             elif live:
                 slot = sorted(live)[op % len(live)]
                 if live[slot] > 0 and op % 3:
