@@ -222,6 +222,7 @@ def ec_sghmc(
         )
 
         # -- s-periodic exchange (the ONLY cross-chain collective) ----------
+        @jax.named_scope("sampler.exchange")
         def do_sync(operand):
             new_c, upd = operand
             # workers push theta^i (post-update), server replies with c.

@@ -63,6 +63,7 @@ def sghmc(
             step=jnp.zeros((), jnp.int32),
         )
 
+    @jax.named_scope("sampler.update")
     def update(grads, state, params=None, rng=None):
         del params
         eps = schedule(state.step)

@@ -1,5 +1,6 @@
 """Unified telemetry: structured metrics, host-side event tracing with
-Perfetto export, structured logging, and run-manifest sinks.
+Perfetto export (and profiler annotations), structured logging, and the
+run manifest.
 
 Quick start::
 
@@ -26,7 +27,7 @@ from repro.obs.metrics import (
     default_registry,
     reset_default,
 )
-from repro.obs.sinks import JsonlSink, run_manifest
+from repro.obs.sinks import run_manifest
 from repro.obs.trace import Tracer, disable as disable_tracing, enable as enable_tracing
 from repro.obs.validate import validate_manifest, validate_trace
 
@@ -39,7 +40,6 @@ __all__ = [
     "MetricsRegistry",
     "default_registry",
     "reset_default",
-    "JsonlSink",
     "run_manifest",
     "Tracer",
     "enable_tracing",

@@ -22,7 +22,6 @@ in-flight decode work).
 """
 from __future__ import annotations
 
-import json
 import math
 
 
@@ -168,10 +167,6 @@ RENAMES = {
         "admitted": "admitted_total",
         "retired": "retired_total",
     },
-    "executor": {
-        "chunks": "chunks_total",
-        "steps": "steps_total",
-    },
 }
 
 _COUNTER_SUFFIX = "_total"
@@ -230,10 +225,6 @@ class MetricsRegistry:
             m = self._metrics[name]
             out[name] = m.summary() if isinstance(m, Histogram) else m.value
         return out
-
-    def dump_jsonl(self, path) -> None:
-        with open(path, "a") as f:
-            f.write(json.dumps({"kind": "metrics", **self.snapshot()}) + "\n")
 
 
 _REGISTRY = MetricsRegistry()

@@ -1,14 +1,12 @@
-"""Output sinks: the shared run manifest and a JSONL metrics stream.
+"""The shared run manifest.
 
 The manifest is the provenance block stamped into every artifact a run
-emits — ``trace.json`` (``otherData.manifest``), each ``BENCH_*.json``
-(``manifest`` key, via ``benchmarks/common.py``), and the JSONL metrics
-stream header — so any two artifacts can be matched to the same code +
-backend + device state after the fact.
+emits — ``trace.json`` (``otherData.manifest``) and each ``BENCH_*.json``
+(``manifest`` key, via ``benchmarks/common.py``) — so any two artifacts
+can be matched to the same code + backend + device state after the fact.
 """
 from __future__ import annotations
 
-import json
 import platform
 import subprocess
 import sys
@@ -62,35 +60,3 @@ def run_manifest() -> dict:
         "platform": platform.platform(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
     }
-
-
-class JsonlSink:
-    """Append-one-JSON-object-per-line stream.  First line is the run
-    manifest; ``metrics()`` lines carry periodic registry snapshots and
-    ``summary()`` closes the run."""
-
-    def __init__(self, path):
-        self.path = path
-        self._wrote_header = False
-
-    def _write(self, obj: dict) -> None:
-        with open(self.path, "a") as f:
-            f.write(json.dumps(obj) + "\n")
-
-    def header(self, manifest: dict | None = None) -> None:
-        self._write({"kind": "manifest", **(manifest or run_manifest())})
-        self._wrote_header = True
-
-    def metrics(self, snapshot: dict, step: int | None = None) -> None:
-        if not self._wrote_header:
-            self.header()
-        rec = {"kind": "metrics"}
-        if step is not None:
-            rec["step"] = step
-        rec.update(snapshot)
-        self._write(rec)
-
-    def summary(self, snapshot: dict, **extra) -> None:
-        if not self._wrote_header:
-            self.header()
-        self._write({"kind": "summary", **extra, **snapshot})

@@ -20,6 +20,13 @@ visible without ever being allowed to change it:
   Events that happen inside compiled programs (the s-periodic sync
   collective) are host-RECONSTRUCTED at chunk boundaries from static
   cadence metadata (DESIGN.md §11).
+* **On the profiler's clock too.**  An enabled span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  session shows the program's spans on its host plane, on the device
+  trace's clock; without a session the annotation records nothing.
+  ``Tracer.annotate`` gives the annotation alone, for a region the ring
+  must not see.  jax is imported on the first enabled span, so this
+  module imports without it.
 * **Ring buffer, not a log.**  Events land in a preallocated list at a
   monotonically increasing cursor (mod capacity); old events are
   overwritten, never reallocated, and ``dropped`` counts the overwrites.
@@ -67,9 +74,22 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# jax.profiler.TraceAnnotation, looked up on the first enabled span (tests
+# monkeypatch this to spy on it)
+_annotation = None
+
+
+def annotation(name: str):
+    """A profiler annotation named ``name`` (name only: keyword arguments
+    would cost a string format per event while a session records)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation(name)
+
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
         self._tr = tr
@@ -77,13 +97,16 @@ class _Span:
         self.cat = cat
         self.args = args
         self._t0 = 0
+        self._ann = annotation(name)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = _now()
         return self
 
     def __exit__(self, *exc):
         self._tr._record(("X", self.name, self.cat, self._t0, _now() - self._t0, self.args))
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -117,6 +140,14 @@ class Tracer:
         if not self.enabled:
             return _NOOP
         return _Span(self, name, cat, args)
+
+    def annotate(self, name: str):
+        """A profiler annotation alone: the region shows in a profile, and
+        the ring records nothing (for a region whose start must not read as
+        an event).  The shared no-op on a disabled tracer."""
+        if not self.enabled:
+            return _NOOP
+        return annotation(name)
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
         """Record a zero-duration ('i') event."""

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import apply_updates, tree_broadcast_axis0
-from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.diagnostics import (
     BatchMeansState,
@@ -378,7 +377,6 @@ class ChainExecutor:
         t_run, t_abs = 0, int(start_step)
         t0 = time.perf_counter()
         stopped = False
-        chunks = 0
         while t_run < num_steps and not stopped:
             n = min(self.chunk_steps, num_steps - t_run)
             fn, n_outer, thin = self._compile(n, sweep, key_axis)
@@ -388,7 +386,6 @@ class ChainExecutor:
             with obs_trace.get().span("executor.chunk", cat="executor",
                                       step=t_abs, n=n):
                 carry, outs = fn(hyper, key, carry, xs)
-            chunks += 1
             t_run += n
             t_abs += n
             if self.trace_fn is not None:
@@ -410,10 +407,6 @@ class ChainExecutor:
         with obs_trace.get().span("executor.settle", cat="executor", step=t_abs):
             jax.block_until_ready(carry["params"])
         wall = time.perf_counter() - t0
-        reg = obs_metrics.default_registry()
-        reg.counter("executor.chunks_total").inc(chunks)
-        reg.counter("executor.steps_total").inc(t_run)
-        reg.histogram("executor.run_wall_s").observe(wall)
 
         axis = 1 if sweep else 0
         cat = lambda ts: jax.tree.map(lambda *xs_: np.concatenate(xs_, axis=axis), *ts)

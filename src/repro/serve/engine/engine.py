@@ -82,9 +82,10 @@ class ServeReport:
 
     def latency_percentiles(self) -> dict:
         """p50/p99 of request completion latency and first-token latency
-        (seconds, queueing included)."""
+        (seconds, queueing included; a first token counts once the host
+        holds it, so the prefill's device time is inside)."""
         lat = np.asarray([r.latency_s for r in self.results], np.float64)
-        ftl = np.asarray([r.first_token_s for r in self.results], np.float64)
+        ftl = np.asarray([r.first_token_ready_s for r in self.results], np.float64)
         pct = lambda a, q: float(np.percentile(a, q)) if a.size else float("nan")
         return {
             "latency_p50_s": pct(lat, 50),
@@ -189,6 +190,7 @@ class ServeEngine:
         self._key_admit = jax.random.fold_in(base, 1)
         self.trace_counts: Counter = Counter()
         self.decode_steps = 0
+        self.page_waits = 0  # ticks an FCFS head waited for pages, all runs
         # Multi-device layout (DESIGN.md §7): pooled caches (K, S, ...) shard
         # member/slot over their two leading dims, slot-state arrays shard
         # over slot, members over member; any dim a mesh axis does not divide
@@ -352,6 +354,7 @@ class ServeEngine:
             return jnp.zeros(tok.shape, bool)
         return tok == self.eos_id
 
+    @jax.named_scope("serve.bma_select")
     def _mix_select(self, logits, key):
         """Per-tick BMA mixture + token selection over the slot axis:
         (K, S, V) member logits -> (tokens (S,), mixture logprobs (S, V)).
@@ -370,6 +373,7 @@ class ServeEngine:
         feed = jnp.where(next_done, jnp.int32(self.pad_id), tok)[:, None]
         return emit, feed, next_done, budget - 1, logp
 
+    @jax.named_scope("serve.decode")
     def _decode_fn(self, members, caches, tokens, done, budget, key):
         self.trace_counts["decode"] += 1  # trace-time side effect only
 
@@ -385,6 +389,7 @@ class ServeEngine:
         emit, feed, next_done, budget, logp = self._select_tail(tok, logp, done, budget)
         return emit, feed, new_caches, next_done, budget, logp
 
+    @jax.named_scope("serve.decode")
     def _decode_paged_fn(self, members, pools, tokens, done, budget, tables, ctx, key):
         """Paged twin of :meth:`_decode_fn`.  Block tables (S, M) and context
         lengths (S,) are DATA — page churn never retraces.  The destination
@@ -407,6 +412,7 @@ class ServeEngine:
         emit, feed, next_done, budget, logp = self._select_tail(tok, logp, done, budget)
         return emit, feed, new_pools, next_done, budget, logp
 
+    @jax.named_scope("serve.admit")
     def _admit_fn(self, members, caches, tokens, done, budget, prompt, slot, max_new, key):
         self.trace_counts[f"admit_len{prompt.shape[-1]}"] += 1
 
@@ -432,6 +438,7 @@ class ServeEngine:
         budget = budget.at[slot].set(max_new - 1)
         return new_caches, tokens, done, budget, tok, slot_done, logp
 
+    @jax.named_scope("serve.admit")
     def _admit_paged_fn(self, members, pools, tokens, done, budget, prompt,
                         table_row, slot, max_new, key):
         """Paged twin of :meth:`_admit_fn`: dense prefill (length-shaped,
@@ -476,7 +483,8 @@ class ServeEngine:
             tokens=r.num_tokens, eos=bool(r.hit_eos),
         )
 
-    def _do_admit(self, req: Request, step: int, submit_s: float, active: dict, results: list, wall):
+    def _do_admit(self, req: Request, step: int, submit_s: float, active: dict, results: list,
+                  wall, page_waits: int):
         need = int(req.prompt.size) + req.max_new
         if need > self.max_seq:
             # the non-windowed cache write clamps at max_seq-1, which would
@@ -486,13 +494,18 @@ class ServeEngine:
                 f"engine max_seq={self.max_seq}"
             )
         slot = self.pool.acquire()
-        key = jax.random.fold_in(self._key_admit, req.rid)
-        prompt = jnp.asarray(req.prompt)[None]
-        admit_span = obs_trace.get().span(
+        tracer = obs_trace.get()
+        # queued_ms: schedulable to admitted; page_waits: the ticks of it
+        # spent as the FCFS head refused for pages (the rest waited for
+        # slots).  The span holds the admit's own host work from here on
+        admit_span = tracer.span(
             "serve.admit", cat="serve", rid=req.rid, slot=slot,
             prompt_len=int(req.prompt.size), step=step,
+            queued_ms=(wall() - submit_s) * 1e3, page_waits=page_waits,
         )
         admit_span.__enter__()
+        key = jax.random.fold_in(self._key_admit, req.rid)
+        prompt = jnp.asarray(req.prompt)[None]
         if self.paged:
             table_row = self.pool.admit_blocks(
                 slot, req.prompt, req.max_new, self.registry.version
@@ -524,9 +537,21 @@ class ServeEngine:
         self.pool.caches, self._tokens, self._done, self._budget, tok, slot_done, logp = out
         admit_span.__exit__(None, None, None)
         now = wall()
+        # The host holds the first token once this fetch returns, after the
+        # prefill ran on the device.  A ring event here would end the gap
+        # that readers of the ring take as that wait (they read the next
+        # event's start after serve.admit ends, and a span records its
+        # start), so the fetch is labelled for the profiler alone and
+        # serve.first_token is the first ring event after the admit.
+        with tracer.annotate("serve.first_token.fetch"):
+            first = int(tok)
+        ready = wall()
+        tracer.instant("serve.first_token", cat="serve", rid=req.rid, slot=slot,
+                       wait_ms=(ready - now) * 1e3)
         res = RequestResult(rid=req.rid, prompt_len=int(req.prompt.size), admitted_step=step)
         res.first_token_s = now - submit_s
-        act = _Active(result=res, submit_s=submit_s, tokens=[int(tok)])
+        res.first_token_ready_s = ready - submit_s
+        act = _Active(result=res, submit_s=submit_s, tokens=[first])
         if self.record_logprobs:
             act.logprobs.append(np.asarray(logp))
         if bool(slot_done):
@@ -551,6 +576,7 @@ class ServeEngine:
         active: dict[int, _Active] = {}
         results: list[RequestResult] = []
         submit_s: dict[int, float] = {}
+        page_waits: dict[int, int] = {}
         step = 0
         steps_at_start = self.decode_steps
         t0 = time.perf_counter()
@@ -577,9 +603,12 @@ class ServeEngine:
                             f"page pool (free={self.pool.alloc.free_blocks} blocks "
                             f"of {self.pool.block_size})"
                         )
+                    page_waits[req.rid] = page_waits.get(req.rid, 0) + 1
+                    self.page_waits += 1
                     break
                 queue.pop()
-                self._do_admit(req, step, submit_s[req.rid], active, results, wall)
+                self._do_admit(req, step, submit_s[req.rid], active, results, wall,
+                               page_waits.pop(req.rid, 0))
             if self.refresher is not None and self.refresh_every:
                 # every tick: flip-if-ready + credit-paced micro-chunk
                 # dispatch (one full chunk per refresh_every ticks) — no
@@ -587,59 +616,63 @@ class ServeEngine:
                 self.refresher.pump(step)
             self._note_version()  # promotions (any source) invalidate stale prefixes
             if active:
-                # span covers dispatch AND the emissions fetch below — the
-                # true per-tick wall time including device compute
-                tick_span = obs_trace.get().span(
-                    "serve.decode_tick", cat="serve", step=step, active=len(active),
-                )
-                tick_span.__enter__()
-                key = jax.random.fold_in(self._key_decode, step)
-                if self.paged:
-                    # Host-side growth first: make sure every live slot owns
-                    # the page its fed token writes into, then ship the
-                    # tables/positions as data.
-                    for slot in active:
-                        self.pool.ensure_decode_block(slot)
-                    emit, feed, caches, done, budget, logp = self._decode(
-                        self._members(),
-                        self.pool.caches,
-                        self._tokens,
-                        self._done,
-                        self._budget,
-                        # jnp.array COPIES (asarray may zero-copy alias the
-                        # allocator's live numpy buffers, which mutate under
-                        # the async dispatch — advance()/ensure_decode_block
-                        # run before the tick's compute necessarily does)
-                        jnp.array(self.pool.tables),
-                        jnp.array(self.pool.ctx),
-                        key,
-                    )
-                    for slot in active:  # fed token consumed position ctx
-                        self.pool.advance(slot)
-                else:
-                    emit, feed, caches, done, budget, logp = self._decode(
-                        self._members(),
-                        self.pool.caches,
-                        self._tokens,
-                        self._done,
-                        self._budget,
-                        key,
-                    )
-                self.pool.caches = caches
-                self._tokens, self._done, self._budget = feed, done, budget
-                self.decode_steps += 1
-                emit_np = np.asarray(emit)
-                done_np = np.asarray(done)
-                logp_np = np.asarray(logp) if self.record_logprobs else None
-                tick_span.__exit__(None, None, None)
-                now = wall()
-                for slot, act in list(active.items()):
-                    act.tokens.append(int(emit_np[slot]))
-                    if self.record_logprobs:
-                        act.logprobs.append(logp_np[slot])
-                    if done_np[slot]:
-                        self._finalize(slot, act, step, now, results)
-                        del active[slot]
+                tracer = obs_trace.get()
+                # the tick's span covers dispatch AND the emissions fetch —
+                # the true per-tick wall time including device compute
+                with tracer.span("serve.decode_tick", cat="serve", step=step,
+                                 active=len(active)):
+                    with tracer.span("serve.tick.dispatch", cat="serve"):
+                        key = jax.random.fold_in(self._key_decode, step)
+                        if self.paged:
+                            # Host-side growth first: make sure every live slot
+                            # owns the page its fed token writes into, then ship
+                            # the tables/positions as data.
+                            for slot in active:
+                                self.pool.ensure_decode_block(slot)
+                            out = self._decode(
+                                self._members(),
+                                self.pool.caches,
+                                self._tokens,
+                                self._done,
+                                self._budget,
+                                # jnp.array COPIES (asarray may zero-copy alias
+                                # the allocator's live numpy buffers, which
+                                # mutate under the async dispatch —
+                                # advance()/ensure_decode_block run before the
+                                # tick's compute necessarily does)
+                                jnp.array(self.pool.tables),
+                                jnp.array(self.pool.ctx),
+                                key,
+                            )
+                        else:
+                            out = self._decode(
+                                self._members(),
+                                self.pool.caches,
+                                self._tokens,
+                                self._done,
+                                self._budget,
+                                key,
+                            )
+                    if self.paged:
+                        for slot in active:  # fed token consumed position ctx
+                            self.pool.advance(slot)
+                    emit, feed, caches, done, budget, logp = out
+                    self.pool.caches = caches
+                    self._tokens, self._done, self._budget = feed, done, budget
+                    self.decode_steps += 1
+                    with tracer.span("serve.tick.fetch", cat="serve"):
+                        emit_np = np.asarray(emit)
+                        done_np = np.asarray(done)
+                        logp_np = np.asarray(logp) if self.record_logprobs else None
+                with tracer.span("serve.tick.collect", cat="serve"):
+                    now = wall()
+                    for slot, act in list(active.items()):
+                        act.tokens.append(int(emit_np[slot]))
+                        if self.record_logprobs:
+                            act.logprobs.append(logp_np[slot])
+                        if done_np[slot]:
+                            self._finalize(slot, act, step, now, results)
+                            del active[slot]
             step += 1
         if active:  # max_steps truncation: finalize + recycle in-flight slots
             self._done = self._done.at[jnp.asarray(sorted(active), jnp.int32)].set(True)
@@ -685,10 +718,11 @@ class ServeEngine:
         else:
             reg.absorb("serve.pool", report.pool)
         reg.absorb("serve.registry", report.registry)
+        reg.absorb("serve.admit", {"page_waits_total": self.page_waits})
         if report.refresher:
             reg.absorb("serve.refresh", report.refresher)
         lat = reg.histogram("serve.request.latency_s")
         ftl = reg.histogram("serve.request.first_token_s")
         for r in report.results:
             lat.observe(r.latency_s)
-            ftl.observe(r.first_token_s)
+            ftl.observe(r.first_token_ready_s)

@@ -45,7 +45,10 @@ class RequestResult:
     tokens: np.ndarray = field(default_factory=lambda: np.zeros((0,), np.int32))
     admitted_step: int = -1
     finished_step: int = -1
+    # first_token_s: when the admit returned, before the prefill finished on
+    # the device; first_token_ready_s: when the host held the token
     first_token_s: float = float("nan")
+    first_token_ready_s: float = float("nan")
     latency_s: float = float("nan")
     hit_eos: bool = False
     truncated: bool = False  # run() hit max_steps with this request in flight

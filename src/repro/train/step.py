@@ -44,6 +44,7 @@ def make_grad_fn(
         u, aux = jax.vmap(per_chain)(params, batch)
         return jnp.sum(u), aux
 
+    @jax.named_scope("sampler.grad")
     def grad_fn(targets, batch):
         (u, (sum_nll, count)), grads = jax.value_and_grad(potential, has_aux=True)(
             targets, batch

@@ -25,7 +25,7 @@ from repro import core
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.log import get_logger
-from repro.obs.sinks import MANIFEST_KEYS, JsonlSink, run_manifest
+from repro.obs.sinks import MANIFEST_KEYS, run_manifest
 from repro.obs.validate import REQUIRED, validate_manifest, validate_trace
 from repro.run import ChainExecutor
 from repro.serve.engine import (
@@ -120,14 +120,6 @@ class TestMetrics:
         snap = reg.snapshot()
         assert snap["serve.alloc.blocks_high_water"] == 7
         assert snap["serve.alloc.prefix_hits_total"] == 3
-
-    def test_dump_jsonl(self, tmp_path):
-        reg = obs_metrics.MetricsRegistry()
-        reg.counter("a_total").inc(2)
-        p = tmp_path / "m.jsonl"
-        reg.dump_jsonl(p)
-        rec = json.loads(p.read_text().splitlines()[0])
-        assert rec == {"kind": "metrics", "a_total": 2}
 
 
 # ---------------------------------------------------------------------------
@@ -264,16 +256,6 @@ class TestExportAndValidate:
         assert validate_manifest(m) == []
         assert m["device_count"] >= 1
         assert m["backend"] in ("cpu", "gpu", "tpu")
-
-    def test_jsonl_sink_stream(self, tmp_path):
-        p = tmp_path / "run.jsonl"
-        sink = JsonlSink(p)
-        sink.metrics({"a_total": 1}, step=7)
-        sink.summary({"a_total": 2}, bench="x")
-        lines = [json.loads(line) for line in p.read_text().splitlines()]
-        assert [rec["kind"] for rec in lines] == ["manifest", "metrics", "summary"]
-        assert validate_manifest({k: lines[0][k] for k in lines[0] if k != "kind"}) == []
-        assert lines[1]["step"] == 7 and lines[2]["bench"] == "x"
 
 
 # ---------------------------------------------------------------------------
@@ -445,3 +427,144 @@ class TestTracedServe:
         assert snap["serve.pool.slots"] == 2
         assert snap["serve.refresh.micro_chunks_total"] >= 1
         assert snap["serve.request.latency_s"]["count"] == len(report.results)
+
+
+# ---------------------------------------------------------------------------
+# program spans: profiler annotations, the split tick, the first token and
+# the admit queue
+# ---------------------------------------------------------------------------
+
+
+class _SpyAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` and logs its use."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs  # name only
+        self.name = name
+        self.log.append(("new", name))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def spy_annotation(monkeypatch):
+    monkeypatch.setattr(_SpyAnnotation, "log", [])
+    monkeypatch.setattr(obs_trace, "_annotation", _SpyAnnotation)
+    return _SpyAnnotation.log
+
+
+def _tiny_serve(num_requests=4, *, paged=False, num_blocks=None, max_new=6):
+    cfg = tiny_cfg()
+    from repro.models import get_model
+
+    model = get_model(cfg)
+    engine = ServeEngine(cfg, model, member_stack(cfg, model, 2), num_slots=2, max_seq=24,
+                         paged=paged, block_size=8, num_blocks=num_blocks)
+    reqs = synthetic_trace(num_requests, vocab_size=cfg.vocab_size, prompt_lens=(5,),
+                           max_new=max_new, mean_interarrival=1.0, seed=3)
+    return engine.run(reqs)
+
+
+def _spans(events, name):
+    return [e for e in events if e[0] == "X" and e[1] == name]
+
+
+class TestProgramSpans:
+    def test_enabled_span_enters_and_exits_a_profiler_annotation(self, spy_annotation):
+        tr = obs_trace.Tracer(capacity=8)
+        with tr.span("serve.decode_tick", cat="serve", step=1):
+            assert spy_annotation == [("new", "serve.decode_tick"), ("enter", "serve.decode_tick")]
+        assert spy_annotation[-1] == ("exit", "serve.decode_tick")
+        assert tr.names() == {"serve.decode_tick"}
+        # annotate(): the profiler sees the region, the ring does not
+        with tr.annotate("serve.first_token.fetch"):
+            pass
+        assert spy_annotation[-2:] == [("enter", "serve.first_token.fetch"),
+                                       ("exit", "serve.first_token.fetch")]
+        assert len(tr) == 1
+
+    def test_disabled_tracer_makes_no_annotation_and_reads_no_clock(
+            self, spy_annotation, monkeypatch):
+        calls = _count_clock(monkeypatch)
+        tr = obs_trace.Tracer(capacity=4, enabled=False)
+        with tr.span("a"), tr.annotate("b"):
+            tr.instant("c")
+        _tiny_serve(paged=True)  # the module tracer is the disabled NULL
+        assert spy_annotation == [] and calls["n"] == 0
+
+    def test_each_decode_tick_holds_one_dispatch_and_one_fetch(self):
+        tr = obs_trace.enable(capacity=1 << 14)
+        report = _tiny_serve(paged=True)
+        events = tr.events()
+        ticks = _spans(events, "serve.decode_tick")
+        assert len(ticks) == report.decode_steps > 0
+        inside = lambda e, t: t[3] <= e[3] and e[3] + e[4] <= t[3] + t[4]
+        for name in ("serve.tick.dispatch", "serve.tick.fetch"):
+            spans = _spans(events, name)
+            assert len(spans) == len(ticks)
+            for t in ticks:
+                assert sum(inside(e, t) for e in spans) == 1, (name, t)
+        # collect follows its tick, outside it
+        collects = _spans(events, "serve.tick.collect")
+        assert len(collects) == len(ticks)
+        for t, c in zip(ticks, collects):
+            assert c[3] >= t[3] + t[4]
+
+    def test_first_token_is_the_first_ring_event_after_its_admit(self):
+        tr = obs_trace.enable(capacity=1 << 14)
+        report = _tiny_serve(paged=True)
+        events = tr.events()
+        admits = {e[5]["rid"]: i for i, e in enumerate(events)
+                  if e[0] == "X" and e[1] == "serve.admit"}
+        firsts = [e[5]["rid"] for e in events if e[1] == "serve.first_token"]
+        assert sorted(firsts) == sorted(admits) == [r.rid for r in report.results]
+        starts = sorted(e[3] for e in events)
+        for rid, i in admits.items():
+            admit = events[i]
+            end = admit[3] + admit[4]
+            # the first event to START after the admit ends, in time and in
+            # the ring, is this request's first token
+            nxt = min((e for e in events if e[3] >= end), key=lambda e: e[3])
+            assert nxt[1] == "serve.first_token" and nxt[5]["rid"] == rid
+            assert nxt[3] == starts[np.searchsorted(starts, end)]
+            assert nxt[5]["wait_ms"] >= 0.0 and nxt[5]["slot"] == admit[5]["slot"]
+
+    def test_first_token_ready_counts_the_fetch(self):
+        report = _tiny_serve()
+        for r in report.results:
+            assert r.first_token_ready_s >= r.first_token_s >= 0.0
+        ready = np.asarray([r.first_token_ready_s for r in report.results])
+        pct = report.latency_percentiles()
+        assert pct["first_token_p50_s"] == pytest.approx(float(np.percentile(ready, 50)))
+        hist = obs_metrics.default_registry().snapshot()["serve.request.first_token_s"]
+        assert hist["sum"] == pytest.approx(float(ready.sum()))
+
+    def test_page_waits_and_queued_ms(self):
+        # pages for one request at a time (2 of 2 usable pages each) beside
+        # two free slots: the second of two requests waits for pages.  The
+        # first run warms the host's one-off work out of queued_ms
+        _tiny_serve(4, paged=True, num_blocks=3, max_new=8)
+        obs_metrics.reset_default()
+        tr = obs_trace.enable(capacity=1 << 14)
+        report = _tiny_serve(4, paged=True, num_blocks=3, max_new=8)
+        admits = sorted((e[5] for e in _spans(tr.events(), "serve.admit")),
+                        key=lambda a: a["page_waits"])
+        assert len(admits) == len(report.results) == 4
+        waits = [a["page_waits"] for a in admits]
+        assert waits[-1] > 0
+        queued = [a["queued_ms"] for a in admits]
+        assert all(q >= 0.0 for q in queued)
+        # queued_ms grows with the ticks waited for pages
+        for a, b in zip(admits, admits[1:]):
+            if b["page_waits"] > a["page_waits"]:
+                assert b["queued_ms"] > a["queued_ms"]
+        snap = obs_metrics.default_registry().snapshot()
+        assert snap["serve.admit.page_waits_total"] == sum(waits)
+
