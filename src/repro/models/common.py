@@ -147,7 +147,9 @@ class ModelConfig:
     remat: str = "full"
     # dispatch attention through the Pallas flash kernel (interpret-mode on
     # CPU; compiled on TPU). The §Perf lever that removes score
-    # materialization; default off = paper-faithful XLA baseline.
+    # materialization; default off = paper-faithful XLA baseline.  Paged
+    # decode takes its Pallas kernel on TPU either way; the flag sends it
+    # there elsewhere too (interpret mode).
     use_flash_kernel: bool = False
 
     @property

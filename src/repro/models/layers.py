@@ -221,7 +221,8 @@ def decode_attention(cfg: ModelConfig, p, x, cache, t, window: Optional[int]):
     return out, {"k": new_k, "v": new_v}
 
 
-def paged_decode_attention(cfg: ModelConfig, p, x, pool, block_tables, context_lens, write_block):
+def paged_decode_attention(cfg: ModelConfig, p, x, pool, block_tables, context_lens, write_block,
+                           *, sharded: bool = False):
     """Single-token decode against a block-paged KV pool (DESIGN.md §8).
 
     x: (S, 1, D) — every engine slot jointly (the pool is shared, so slots
@@ -231,9 +232,16 @@ def paged_decode_attention(cfg: ModelConfig, p, x, pool, block_tables, context_l
     this step's k/v (page 0 is the sink — done/free slots write there and
     nothing ever reads it).  Returns (out (S, 1, D), new pool).
 
-    Numerics mirror :func:`decode_attention` exactly — einsums in
-    ``compute_dtype``, softcap/softmax in f32, -1e30 masking — so paged vs
-    dense equivalence holds at f32-roundoff tolerance."""
+    On TPU, or where ``cfg.use_flash_kernel`` is set, attention is the
+    paged Pallas kernel, which reads only the pages of live slots up to
+    each slot's position; a slot writing to the sink is done or free, reads
+    nothing and gets a zero output, which the engine discards.  Elsewhere,
+    and where the pools are ``sharded`` across devices (a custom call
+    GSPMD cannot partition), it is the gather path: every slot's whole
+    table gathered, numerics mirroring :func:`decode_attention` exactly —
+    einsums in ``compute_dtype``, softcap/softmax in f32, -1e30 masking —
+    so paged vs dense equivalence holds at f32-roundoff tolerance.  The
+    kernel's scores and softmax are f32 throughout."""
     cd = cfg.compute_dtype
     S = x.shape[0]
     pos = context_lens[:, None].astype(jnp.int32)  # (S, 1)
@@ -243,11 +251,12 @@ def paged_decode_attention(cfg: ModelConfig, p, x, pool, block_tables, context_l
     new_k = pool["k"].at[write_block, off].set(k[:, 0].astype(pool["k"].dtype))
     new_v = pool["v"].at[write_block, off].set(v[:, 0].astype(pool["v"].dtype))
     window = None  # paged pools are non-windowed (guarded at pool creation)
-    if cfg.use_flash_kernel and cfg.mrope_sections is None:
+    if (cfg.use_flash_kernel or jax.default_backend() == "tpu") and not sharded:
         from repro.kernels.ops import paged_attention as _paged
 
+        live_ctx = jnp.where(write_block == 0, -1, context_lens)
         out = _paged(
-            q[:, 0], new_k, new_v, block_tables, context_lens,
+            q[:, 0], new_k, new_v, block_tables, live_ctx,
             scale=_scale(cfg), window=window, softcap=cfg.attn_logit_softcap,
         )[:, None]  # (S, 1, Hkv, G, dh)
     else:

@@ -500,10 +500,10 @@ def paged_prefill_write(cfg: ModelConfig, pools, slot_cache, table_row, block_si
     return out
 
 
-def _paged_decode_block(cfg, kind, p, x, pool, block_tables, context_lens, write_block):
+def _paged_decode_block(cfg, kind, p, x, pool, block_tables, context_lens, write_block, sharded):
     h, new_attn = L.paged_decode_attention(
         cfg, p["attn"], _norm(cfg, x, p["ln1"]), pool["attn"],
-        block_tables, context_lens, write_block,
+        block_tables, context_lens, write_block, sharded=sharded,
     )
     if cfg.sandwich_norm:
         h = _norm(cfg, h, p["post_ln1"])
@@ -516,11 +516,13 @@ def _paged_decode_block(cfg, kind, p, x, pool, block_tables, context_lens, write
 
 
 def paged_decode_step(cfg: ModelConfig, params, pools, tokens, block_tables,
-                      context_lens, write_block):
+                      context_lens, write_block, *, sharded: bool = False):
     """All-slots-jointly decode: tokens (S, 1), block_tables (S, M) int32,
     context_lens (S,) int32 current positions, write_block (S,) int32
-    destination pages.  Returns (logits (S, 1, V), new pools).  The shared
-    page pools preclude a slot vmap — the slot axis is the batch axis."""
+    destination pages (0, the sink, for a done or free slot); ``sharded``:
+    the pools are split across devices.  Returns (logits (S, 1, V), new
+    pools).  The shared page pools preclude a slot vmap — the slot axis is
+    the batch axis."""
     x = L.embed(cfg, params["embed"], tokens)
     P, n_periods, rem_kinds = _layout(cfg)
 
@@ -531,7 +533,7 @@ def paged_decode_step(cfg: ModelConfig, params, pools, tokens, block_tables,
         for i in range(P):
             x, new_p[str(i)] = _paged_decode_block(
                 cfg, cfg.pattern[i], pslice[str(i)], x, poolslice[str(i)],
-                block_tables, context_lens, write_block,
+                block_tables, context_lens, write_block, sharded,
             )
         return x, new_p
 
@@ -542,7 +544,7 @@ def paged_decode_step(cfg: ModelConfig, params, pools, tokens, block_tables,
         for i, kind in enumerate(rem_kinds):
             x, new_pools["rem"][str(i)] = _paged_decode_block(
                 cfg, kind, params["rem"][str(i)], x, pools["rem"][str(i)],
-                block_tables, context_lens, write_block,
+                block_tables, context_lens, write_block, sharded,
             )
     x = _norm(cfg, x, params["final_norm"])
     logits = L.final_logits(cfg, params["embed"], x)

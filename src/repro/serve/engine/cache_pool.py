@@ -536,6 +536,11 @@ class PagedCachePool:
     def advance(self, slot: int) -> None:
         self.alloc.advance(slot)
 
+    def kv_pages(self, slots) -> int:
+        """Pages a decode tick attends for ``slots``: those holding each
+        slot's positions up to its current one, ceil((ctx + 1) / bs)."""
+        return sum(int(self.alloc.ctx[s]) // self.block_size + 1 for s in slots)
+
     def invalidate_version(self, version: int) -> int:
         """Drop prefix entries superseded by a registry promotion (the
         engine calls this once per version bump)."""

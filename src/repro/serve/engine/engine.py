@@ -403,8 +403,11 @@ class ServeEngine:
         write_block = jnp.where(done, 0, tables[jnp.arange(S), j])  # (S,)
 
         def member_step(p, pool):
+            # on a mesh the pools are member-sharded: the kernel, a custom
+            # call GSPMD cannot partition, would gather them whole
             return self.model.paged.decode_step(
-                self.cfg, p, pool, tokens, tables, ctx, write_block
+                self.cfg, p, pool, tokens, tables, ctx, write_block,
+                sharded=self.mesh is not None,
             )
 
         logits, new_pools = jax.vmap(member_step)(members, pools)  # (K, S, 1, V)
@@ -619,8 +622,10 @@ class ServeEngine:
                 tracer = obs_trace.get()
                 # the tick's span covers dispatch AND the emissions fetch —
                 # the true per-tick wall time including device compute
-                with tracer.span("serve.decode_tick", cat="serve", step=step,
-                                 active=len(active)):
+                tick_args = {"step": step, "active": len(active)}
+                if self.paged:
+                    tick_args["kv_pages"] = self.pool.kv_pages(active)
+                with tracer.span("serve.decode_tick", cat="serve", **tick_args):
                     with tracer.span("serve.tick.dispatch", cat="serve"):
                         key = jax.random.fold_in(self._key_decode, step)
                         if self.paged:

@@ -517,6 +517,31 @@ class TestProgramSpans:
         for t, c in zip(ticks, collects):
             assert c[3] >= t[3] + t[4]
 
+    def test_decode_tick_counts_the_live_pages(self):
+        """``kv_pages`` of each tick's span is the pages the tick attends:
+        ceil((ctx + 1) / bs) summed over the slots that are not done, from
+        the tables, positions and done mask the tick hands the device."""
+        cfg = tiny_cfg()
+        from repro.models import get_model
+
+        model = get_model(cfg)
+        engine = ServeEngine(cfg, model, member_stack(cfg, model, 2), num_slots=3,
+                             max_seq=24, paged=True, block_size=4)
+        sent, decode = [], engine._decode
+
+        def spy(members, pools, tokens, done, budget, tables, ctx, key):
+            sent.append((np.asarray(done), np.asarray(ctx)))
+            return decode(members, pools, tokens, done, budget, tables, ctx, key)
+
+        engine._decode = spy
+        reqs = synthetic_trace(5, vocab_size=cfg.vocab_size, prompt_lens=(3, 6, 9),
+                               max_new=7, mean_interarrival=1.0, seed=4)
+        tr = obs_trace.enable(capacity=1 << 14)
+        engine.run(reqs)
+        got = [e[5]["kv_pages"] for e in _spans(tr.events(), "serve.decode_tick")]
+        want = [int(sum(c // 4 + 1 for c in ctx[~done])) for done, ctx in sent]
+        assert got == want and len(set(got)) > 2
+
     def test_first_token_is_the_first_ring_event_after_its_admit(self):
         tr = obs_trace.enable(capacity=1 << 14)
         report = _tiny_serve(paged=True)

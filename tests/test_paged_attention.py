@@ -39,6 +39,12 @@ def _paged_case(key, *, B, Hkv, G, d, bs, M, ragged=True):
     return q, k_pages, v_pages, tables, ctx
 
 
+def _live_reference(q, k, v, tab, ctx, **kw):
+    """The reference for live sequences (ctx >= 0); zero for the others."""
+    want = ref.paged_attention(q, k, v, tab, jnp.maximum(ctx, 0), **kw)
+    return jnp.where((ctx >= 0)[:, None, None, None], want, 0.0)
+
+
 class TestPagedAttentionKernel:
     @pytest.mark.parametrize("bs", [8, 16, 64])
     def test_matches_dense_reference_across_block_sizes(self, bs):
@@ -48,6 +54,61 @@ class TestPagedAttentionKernel:
         got = kernels.paged_attention(q, k, v, tab, ctx)
         want = ref.paged_attention(q, k, v, tab, ctx)
         np.testing.assert_allclose(got, want, atol=2e-6)
+
+    # M = 7 pages a row, bs = 8: contexts ending mid-page, on the last
+    # position of a page, on the first of the next, on the table's last
+    # entry; -1 marks a done or free sequence (no pages, zero output)
+    @pytest.mark.parametrize("ctx", [
+        (-1, 20, -1, 3, -1),
+        (11, 15, 16, 0, 7),
+        (55, 55, 47, 48, 8),
+        (-1, -1, -1, -1, -1),
+    ], ids=["dead-beside-live", "mid-page-and-boundaries", "last-entry", "all-dead"])
+    @pytest.mark.parametrize("pps", [1, 3, 7], ids=lambda n: f"pps{n}")
+    def test_context_edges_and_chunking(self, monkeypatch, ctx, pps):
+        """Pages per chunk that do and do not divide each sequence's live
+        pages (pps 3 over 1..7 pages), one page a chunk, a whole row a
+        chunk, on a permuted table."""
+        import importlib
+
+        pa = importlib.import_module("repro.kernels.paged_attention")
+        B, Hkv, G, d, bs, M = 5, 2, 2, 16, 8, 7
+        q, k, v, _, _ = _paged_case(
+            jax.random.PRNGKey(pps), B=B, Hkv=Hkv, G=G, d=d, bs=bs, M=M
+        )
+        perm = 1 + np.random.default_rng(pps).permutation(B * M)
+        tab = jnp.asarray(perm.reshape(B, M), jnp.int32)
+        ctx = jnp.asarray(ctx, jnp.int32)
+        page_bytes = bs * Hkv * d * k.dtype.itemsize
+        monkeypatch.setattr(pa, "KV_VMEM_BYTES", 4 * page_bytes * pps)
+        assert pa.pages_per_step(bs, Hkv * d * k.dtype.itemsize, M) == pps
+        got = pa.paged_attention(q, k, v, tab, ctx)
+        np.testing.assert_allclose(got, _live_reference(q, k, v, tab, ctx), atol=2e-6)
+
+    def test_vmap_over_members_shares_tables(self):
+        """How the engine calls it: vmapped over K=2 members, queries and
+        pools batched, tables and contexts shared — one kernel with the
+        members on its grid, each member its own reference."""
+        q, k, v, tab, ctx = _paged_case(
+            jax.random.PRNGKey(11), B=4, Hkv=2, G=2, d=16, bs=8, M=4
+        )
+        ctx = ctx.at[2].set(-1)
+        qs, ks, vs = (jnp.stack([x, -0.5 * x]) for x in (q, k, v))
+        fn = jax.vmap(lambda a, b, c: kernels.paged_attention(a, b, c, tab, ctx))
+        jaxpr = str(jax.make_jaxpr(fn)(qs, ks, vs))
+        assert jaxpr.count("pallas_call") == 1 and "GridMapping(grid=(2,)" in jaxpr
+        got = fn(qs, ks, vs)
+        for m in range(2):
+            np.testing.assert_allclose(
+                got[m], _live_reference(qs[m], ks[m], vs[m], tab, ctx), atol=2e-6
+            )
+        # contexts that differ per member: one kernel call per member
+        ctxs = jnp.stack([ctx, ctx[::-1]])
+        got = jax.vmap(kernels.paged_attention, in_axes=(0, 0, 0, None, 0))(qs, ks, vs, tab, ctxs)
+        for m in range(2):
+            np.testing.assert_allclose(
+                got[m], _live_reference(qs[m], ks[m], vs[m], tab, ctxs[m]), atol=2e-6
+            )
 
     @pytest.mark.parametrize(
         "B,Hkv,G,d,bs,M",

@@ -447,6 +447,27 @@ class TestPagedEngineDifferential:
         _assert_equal_reports(dense, paged)
         assert eng.decode_trace_count == 1
 
+    # pools of two requests' worst-case growth, for three slots
+    @pytest.mark.parametrize("block_size, num_blocks", [(4, 10), (8, 6)])
+    def test_kernel_path_matches_gather_path(self, setup, block_size, num_blocks):
+        """The paged Pallas kernel, what TPU serving runs (here interpreted,
+        through ``use_flash_kernel``), against the gather path: a ragged,
+        staggered trace whose slots finish at different ticks (EOS, ragged
+        budgets) and free pages that a tight pool hands to later admissions
+        mid-batch; done and free slots read no page."""
+        cfg, model, members = setup
+        reqs = _requests((5, 9, 4, 12, 6, 8, 10, 3), max_new=7, stagger=2, seed=12)
+        reqs = [Request(r.rid, r.prompt, 4 + r.rid % 4, r.arrival_step) for r in reqs]
+        kw = dict(num_slots=3, max_seq=32, eos_id=3, paged=True, block_size=block_size,
+                  num_blocks=num_blocks)
+        _, gather = _run(cfg, model, members, reqs, **kw)
+        eng, kernel = _run(cfg.replace(use_flash_kernel=True), model, members, reqs, **kw)
+        _assert_equal_reports(gather, kernel)
+        assert eng.decode_trace_count == 1
+        assert eng.page_waits > 0  # the pool was tight: pages were reused
+        eng.pool.alloc.check()
+        assert eng.pool.alloc.used_blocks == 0
+
     def test_tight_pool_defers_admission_but_completes(self, setup):
         """A page pool too small for all slots at once: head-of-line waits
         for completions, every request still finishes, and the admission
@@ -560,4 +581,27 @@ class TestShardedPagedServeEngine:
                          mesh=make_engine_mesh(2, 4), **kw)
         assert eng.decode_trace_count == 1, rep1.trace_counts
         _assert_equal_reports(rep0, rep1)
+        eng.pool.alloc.check()
+
+    def test_mesh_paged_keeps_the_gather_path(self, setup):
+        """Where the kernel path is on, the member-sharded pools of a mesh
+        keep the gather path (the kernel is a custom call GSPMD cannot
+        partition): one compiled decode program, no kernel in it, and
+        tokens equal to the unsharded run on the kernel."""
+        util.require_devices(util.MULTIDEVICE_DEVICES)
+        from repro.launch.mesh import make_engine_mesh
+
+        cfg, model, members = setup
+        kcfg = cfg.replace(use_flash_kernel=True)
+        reqs = _requests((5, 9, 7, 12, 6), max_new=5, stagger=1, seed=10)
+        kw = dict(num_slots=2, max_seq=32, paged=True, block_size=8)
+        _, rep0 = _run(kcfg, model, members, reqs, **kw)
+        eng, rep1 = _run(kcfg, model, members, reqs,
+                         mesh=make_engine_mesh(2, 4), **kw)
+        assert eng.decode_trace_count == 1, rep1.trace_counts
+        _assert_equal_reports(rep0, rep1)
+        tables = np.asarray(eng.pool.tables)
+        args = (eng._members(), eng.pool.caches, eng._tokens, eng._done, eng._budget,
+                jnp.asarray(tables), jnp.asarray(eng.pool.ctx), jax.random.PRNGKey(0))
+        assert "pallas_call" not in str(jax.make_jaxpr(eng._decode_paged_fn)(*args))
         eng.pool.alloc.check()

@@ -100,13 +100,54 @@ def test_fused_precond_ec_update_onchip_prng(one_chip):
              (shape, f32), ((1,), jnp.int32))
 
 
+# serve.chat's paged decode: 20 slots, 160-page rows of 16 positions, a
+# 1171-page pool, K=2 members
+SLOTS, ROW_PAGES, BLOCK, POOL_PAGES, MEMBERS = 20, 160, 16, 1171, 2
+
+
 def test_paged_attention(one_chip):
-    B, bs_, pages, blocks = 8, 16, 128, 16
     G = QWEN3_HEADS // QWEN3_KV_HEADS
     fn = functools.partial(paged_attention, interpret=False)
-    kv = ((pages, bs_, QWEN3_KV_HEADS, QWEN3_HEAD_DIM), jnp.bfloat16)
-    _compile(fn, one_chip, ((B, QWEN3_KV_HEADS, G, QWEN3_HEAD_DIM), jnp.bfloat16), kv, kv,
-             ((B, blocks), jnp.int32), ((B,), jnp.int32))
+    kv = ((POOL_PAGES, BLOCK, QWEN3_KV_HEADS, QWEN3_HEAD_DIM), jnp.bfloat16)
+    hlo = _compile(fn, one_chip, ((SLOTS, QWEN3_KV_HEADS, G, QWEN3_HEAD_DIM), jnp.bfloat16),
+                   kv, kv, ((SLOTS, ROW_PAGES), jnp.int32), ((SLOTS,), jnp.int32))
+    assert "paged_attention" in hlo
+
+
+def test_paged_decode_step_vmapped_in_layer_scan(one_chip, monkeypatch):
+    """The engine's decode program: qwen3-0.6b's paged step vmapped over K=2
+    members, the kernel inside the 28-layer scan, the pools viewed without
+    a copy and never gathered whole."""
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import get_model, init_params
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)  # compile the kernel, not its interpreter
+    ops.paged_attention.clear_cache()
+    cfg = configs.get_config("qwen3-0.6b").replace(
+        param_dtype=jnp.float32, compute_dtype=jnp.bfloat16, use_flash_kernel=True
+    )
+    model = get_model(cfg)
+    members = jax.eval_shape(lambda k: jax.vmap(
+        lambda kk: init_params(model.param_specs(cfg), kk))(jax.random.split(k, MEMBERS)),
+        jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: jax.vmap(
+        lambda _: model.paged.make_pools(cfg, POOL_PAGES, BLOCK, jnp.bfloat16))(jnp.arange(MEMBERS)))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(members, pools, tokens, tables, ctx, write_block):
+        return jax.vmap(lambda p, pool: model.paged.decode_step(
+            cfg, p, pool, tokens, tables, ctx, write_block))(members, pools)
+
+    hlo = jax.jit(step, donate_argnums=(1,)).lower(
+        place(members), place(pools), i32(SLOTS, 1), i32(SLOTS, ROW_PAGES), i32(SLOTS),
+        i32(SLOTS)).compile().as_text()
+    ops.paged_attention.clear_cache()
+    assert hlo.count("tpu_custom_call") == 1  # one kernel, in the layer loop
+    # neither the whole-table page gather nor its relayout
+    assert f"bf16[{MEMBERS},{SLOTS},{ROW_PAGES},{BLOCK}," not in hlo
 
 
 def test_flash_attention(one_chip):
