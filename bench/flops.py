@@ -1,37 +1,30 @@
-"""Work from shapes: the FLOPs a qwen3-style decoder needs per token, and
-the FLOPs and bytes of the BMA mixture-and-select step.
+"""Work from shapes: the FLOPs a decoder needs per token, and the FLOPs
+and bytes of the BMA mixture-and-select step.
 
-The per-layer formulas follow the analytic model in the program's roofline
-module (projections, attention over the attended context, gated MLP, tied
-vocabulary head), copied here so that no change to the program can move
-the yardstick.  Recomputation (activation checkpointing) is not counted:
-a utilisation is the work the algorithm needs over the time it took.
+The terms that depend on the architecture (the matmul weights a token
+passes through, attention over a context) are the configuration's
+architecture module's (``bench/arch/<model_type>.py``), kept with the
+benchmark so that no change to the program can move the yardstick.
+Recomputation (activation checkpointing) is not counted: a utilisation is
+the work the algorithm needs over the time it took.
 
 ``cfg`` is a configuration file's dict (Hugging Face key names).
 """
 from __future__ import annotations
 
-
-def _dims(cfg: dict):
-    return (cfg["hidden_size"], cfg["num_attention_heads"], cfg["num_key_value_heads"],
-            cfg["head_dim"], cfg["intermediate_size"], cfg["num_hidden_layers"],
-            cfg["vocab_size"])
+import harness
 
 
 def matmul_params(cfg: dict) -> int:
-    """Weights that take part in a matmul per token: every layer's
-    projections and MLP, plus the vocabulary head (tied or not, it is one
-    D x V product per token; the embedding lookup is a gather)."""
-    D, Hq, Hkv, dh, F, L, V = _dims(cfg)
-    per_layer = D * dh * (Hq + 2 * Hkv) + Hq * dh * D + 3 * D * F
-    return L * per_layer + D * V
+    """Weights that take part in a matmul per token, the vocabulary head
+    included."""
+    return harness.arch(cfg).matmul_params(cfg)
 
 
 def attention_flops(cfg: dict, context: float) -> float:
     """QK^T and AV for one query token attending ``context`` positions,
     over all layers."""
-    D, Hq, Hkv, dh, F, L, V = _dims(cfg)
-    return 4.0 * L * Hq * dh * context
+    return harness.arch(cfg).attention_flops(cfg, context)
 
 
 def forward_flops_per_token(cfg: dict, context: float) -> float:
@@ -43,10 +36,10 @@ def prefill_flops(cfg: dict, prompt_len: int) -> float:
     """One causal prefill of ``prompt_len`` tokens.  The program computes
     the vocabulary head for the last position only, so the head counts
     once."""
-    D, Hq, Hkv, dh, F, L, V = _dims(cfg)
-    body = 2.0 * (matmul_params(cfg) - D * V) * prompt_len
+    head = cfg["hidden_size"] * cfg["vocab_size"]  # one D x V product per token
+    body = 2.0 * (matmul_params(cfg) - head) * prompt_len
     attn = attention_flops(cfg, (prompt_len + 1) / 2.0) * prompt_len
-    return body + attn + 2.0 * D * V
+    return body + attn + 2.0 * head
 
 
 def decode_flops(cfg: dict, context: int) -> float:

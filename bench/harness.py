@@ -3,6 +3,7 @@ checks, compile accounting, the profiler window, the per-layer readers and
 the result line."""
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import math
@@ -38,11 +39,71 @@ def load_cell(name: str, bench: dict | None = None) -> dict:
     cell = cells[name]
     config = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = json.loads((ROOT / config["file"]).read_text())
+    # a file that names no module fails here, before any device work
+    arch(cfg)
+    reference(cfg)
     mix = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
     e2e = [m for m in bench["end_to_end"] if name in m.get("workloads", [name])]
     per_layer = [m for m in bench["per_layer"] if name in m.get("workloads", [name])]
     return {"name": name, "chips": int(cell["chips"]), "cfg": cfg, "mix": mix,
             "end_to_end": e2e, "per_layer": per_layer}
+
+
+def _cell_module(package: str, cfg: dict, key: str):
+    """The module ``<package>.<cfg[key]>``: ``bench/<package>/<cfg[key]>.py``
+    (or a module already registered under that name in ``sys.modules``)."""
+    name = cfg.get(key)
+    if not name:
+        raise CellError(f"the configuration file gives no {key!r}")
+    try:
+        return importlib.import_module(f"{package}.{name}")
+    except ModuleNotFoundError as e:
+        if e.name not in (package, f"{package}.{name}"):
+            raise
+        path = (BENCH / package / f"{name}.py").relative_to(ROOT)
+        raise CellError(f"{key} {name!r} names no module: {path} is missing") from None
+
+
+def arch(cfg: dict):
+    """The architecture module ``bench/arch/<model_type>.py`` that the
+    configuration file names by its ``model_type``.  It provides:
+
+    * ``model_config(cfg)``: the program's ``ModelConfig`` for the file;
+    * ``shapes(cfg)``: the weights' nested dict of leaf shapes, the layout
+      the program loads;
+    * ``NORM_GAINS``: the names of the leaves that are norm gains, drawn
+      as ``1 + weights.GAIN_STD * N(0, 1)``;
+    * ``std(path, cfg)``: the init scale of any other leaf;
+    * ``matmul_params(cfg)``: the weights a token passes through in a
+      matmul, the vocabulary head's ``hidden_size x vocab_size`` included;
+    * ``attention_flops(cfg, context)``: FLOPs of one query token
+      attending ``context`` positions, over all layers;
+    * ``kv_bytes_per_token(cfg)``: one position's KV cache bytes over all
+      layers, for one member."""
+    return _cell_module("arch", cfg, "model_type")
+
+
+def reference(cfg: dict):
+    """The plain reference ``bench/reference/<reference>.py`` that the
+    configuration file names by its ``reference`` key.  It imports nothing
+    of the program and provides, each ``precision`` being ``"f32"``
+    (float32, every matmul at ``highest``) or ``"fp8"`` (the control: matmul
+    operands rounded to float8 e4m3 under a per-tensor scale):
+
+    * ``hidden(cfg, params, tokens, precision="f32", remat=False)``: the
+      final-normed hidden states (S, D) of one sequence of tokens (S,);
+    * ``logits(cfg, params, h, precision="f32")``: vocabulary logits
+      (S, V) of hidden states (S, D);
+    * ``mixture_logprobs(member_logits)``: (K, ..., V) member logits to the
+      log of the mean of the members' next-token distributions;
+    * ``potential_and_grad(cfg, params, batch, *, n_data, weight_decay,
+      precision="f32", rows=None)``: ``(U, nll_per_token, dU/dparams)`` for
+      U = n_data / tokens * NLL + weight_decay * ||params||^2 over a batch
+      of ``tokens``/``labels`` (B, S), its first ``rows`` rows where given.
+
+    ``params`` is one weight set in the layout of the architecture's
+    ``shapes``."""
+    return _cell_module("reference", cfg, "reference")
 
 
 def peak(device_kind: str) -> dict:

@@ -14,8 +14,8 @@ each sequence runs whole.
 tensor into float8 e4m3 (accumulating in float32), the step below the
 bfloat16 the deployments state.  Everything else is the same.
 
-Weights use the layout of ``weights.shapes``.  This module imports nothing
-of the program under test.
+Weights use the layout of ``bench/arch/qwen3.py``'s ``shapes``.  This
+module imports nothing of the program under test.
 """
 from __future__ import annotations
 

@@ -22,7 +22,6 @@ import harness
 import traffic
 import weights
 from harness import note
-from reference import qwen3 as ref
 from reference import sgmcmc
 
 # the compared numbers: the relative gap of each of the first three steps'
@@ -162,6 +161,7 @@ def follow(cfg: dict, mix: dict, seed: int, mesh, seen: dict | None, precision: 
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    ref = harness.reference(cfg)
     dep = cfg["deployment"]
     sd = jnp.dtype(dep["state_dtype"])
     data_key, run_key = jax.random.key(DATA_KEY_STREAM), harness.seed_key(seed, 3)

@@ -20,30 +20,18 @@ import harness
 import traffic
 import weights
 from harness import note
-from reference import qwen3 as ref
+
 
 def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    import jax.numpy as jnp
-
-    from repro import configs
-
-    if cfg["model_type"] != "qwen3" or not cfg["tie_word_embeddings"]:
-        raise harness.CellError("only tied-embedding qwen3 configurations are wired")
-    return configs.get_config("qwen3-0.6b").replace(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=cfg["rms_norm_eps"], param_dtype=jnp.float32, compute_dtype=jnp.bfloat16,
-    )
+    """The program's ModelConfig for a configuration file (its
+    architecture module's)."""
+    return harness.arch(cfg).model_config(cfg)
 
 
 def pool_blocks(cfg: dict, dep: dict) -> int:
-    """Pages of the paged KV pool: ``kv_pool_gib`` of bfloat16 pages for
-    all members, plus the sink page."""
-    per_block = (dep["members"] * cfg["num_hidden_layers"] * 2 * dep["block_size"]
-                 * cfg["num_key_value_heads"] * cfg["head_dim"] * 2)
+    """Pages of the paged KV pool: ``kv_pool_gib`` of pages for all
+    members, plus the sink page."""
+    per_block = dep["members"] * dep["block_size"] * harness.arch(cfg).kv_bytes_per_token(cfg)
     return int(dep["kv_pool_gib"] * 2**30 // per_block) + 1
 
 
@@ -203,6 +191,7 @@ def reference_gaps(cfg: dict, seed: int, sample, prompts: dict, max_seq: int, de
     import jax
     import jax.numpy as jnp
 
+    ref = harness.reference(cfg)
     K = cfg["deployment"]["members"]
     members = weights.make(cfg, seed, K, out_sharding=jax.sharding.SingleDeviceSharding(device))
     rows = 256

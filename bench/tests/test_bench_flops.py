@@ -8,7 +8,8 @@ import flops
 
 CFG = json.loads((Path(__file__).resolve().parents[1] / "configs" /
                   "qwen3-0.6b.ensemble-k2.json").read_text())
-TINY = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
+TINY = {"model_type": "qwen3",
+        "hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
         "intermediate_size": 16, "num_hidden_layers": 3, "vocab_size": 10}
 
 

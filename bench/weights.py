@@ -1,51 +1,20 @@
-"""Seeded random weights for a qwen3-style decoder, in the layout the
-system under test loads (layers stacked on a leading axis), made on the
-device in one jitted call.
+"""Seeded random weights in the layout the system under test loads, made
+on the device in one jitted call.
 
-The layout is written out here from the configuration file, not read from
-the program, so the reference and the program are handed the same arrays
-by a third party.  ``check_layout`` compares it with what the program
-expects before a run starts.
+The layout and the init scales are the configuration's architecture
+module's (``bench/arch/<model_type>.py``), written out there from the
+configuration file, not read from the program, so the reference and the
+program are handed the same arrays by a third party.  ``check_layout``
+compares the layout with what the program expects before a run starts.
 """
 from __future__ import annotations
 
-import math
-
-
-def shapes(cfg: dict) -> dict:
-    """Nested dict of leaf shapes, the program's checkpoint layout."""
-    D, Hq, Hkv, dh = (cfg["hidden_size"], cfg["num_attention_heads"],
-                      cfg["num_key_value_heads"], cfg["head_dim"])
-    F, L, V = cfg["intermediate_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
-    layer = {
-        "ln1": (L, D),
-        "attn": {"wq": (L, D, Hq, dh), "wk": (L, D, Hkv, dh), "wv": (L, D, Hkv, dh),
-                 "wo": (L, Hq, dh, D), "q_norm": (L, dh), "k_norm": (L, dh)},
-        "ln2": (L, D),
-        "mlp": {"w_gate": (L, D, F), "w_up": (L, D, F), "w_down": (L, F, D)},
-    }
-    return {"embed": {"table": (V, D)}, "layers": {"0": layer}, "final_norm": (D,)}
-
+import harness
 
 # norm gains are drawn as 1 + GAIN_STD * N(0, 1): a gain of exactly 1 would
 # let a program that drops a gain, or applies it to the wrong tensor, serve
 # the same logits as the reference
 GAIN_STD = 0.1
-NORM_GAINS = ("ln1", "ln2", "final_norm", "q_norm", "k_norm")
-
-
-def _std(path: tuple, cfg: dict) -> float:
-    """Init scale of a leaf (of its spread about 1, for a norm gain)."""
-    name = path[-1]
-    if name in NORM_GAINS:
-        return GAIN_STD
-    if name == "table":
-        return 0.02
-    if name == "wo":
-        return 1.0 / math.sqrt(cfg["num_attention_heads"] * cfg["head_dim"])
-    if name == "w_down":
-        return 1.0 / math.sqrt(cfg["intermediate_size"])
-    return 1.0 / math.sqrt(cfg["hidden_size"])
 
 
 def _leaves(tree, prefix=()):
@@ -70,14 +39,16 @@ def make(cfg: dict, seed: int, copies: int, out_sharding=None):
     import jax
     import jax.numpy as jnp
 
-    leaves = list(_leaves(shapes(cfg)))
+    arch = harness.arch(cfg)
+    leaves = list(_leaves(arch.shapes(cfg)))
 
     def one(key):
         out: dict = {}
         for i, (path, shape) in enumerate(leaves):
-            val = _std(path, cfg) * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                                      jnp.float32)
-            _set(out, path, 1.0 + val if path[-1] in NORM_GAINS else val)
+            gain = path[-1] in arch.NORM_GAINS
+            std = GAIN_STD if gain else arch.std(path, cfg)
+            val = std * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+            _set(out, path, 1.0 + val if gain else val)
         return out
 
     def build(key):
@@ -95,7 +66,7 @@ def check_layout(cfg: dict, program_specs) -> None:
     """Raise unless the program's parameter tree has exactly this layout."""
     import jax
 
-    ours = {path: tuple(shape) for path, shape in _leaves(shapes(cfg))}
+    ours = {path: tuple(shape) for path, shape in _leaves(harness.arch(cfg).shapes(cfg))}
     flat, _ = jax.tree_util.tree_flatten_with_path(
         program_specs, is_leaf=lambda x: hasattr(x, "shape") and not isinstance(x, dict))
     theirs = {tuple(getattr(k, "key", getattr(k, "name", str(k))) for k in path): tuple(leaf.shape)
