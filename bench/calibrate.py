@@ -92,6 +92,14 @@ class dropped_norm_gains:
         return False
 
 
+# the faults a sampling cell can have; the coupled sampler's exchange can
+# also take its mean over the chip's own chain, without the collective, and
+# its replicated centre can be left as it is.  A state left unchanged reads
+# 1 on the coupled cell's gaps without a run
+SAMPLE_FAULTS = ("unchanged", "half_batch", "norm_gain_dropped")
+COUPLED_FAULTS = ("half_batch", "norm_gain_dropped", "own_chain_mean", "centre_frozen")
+
+
 def sample_readings(cell, seed, mesh, variant=None):
     """Readings of the program (variant None, or "norm_gain_dropped" for
     the program with that fault), or of the control ("control") or a
@@ -119,6 +127,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
+    ap.add_argument("--faults", default=None,
+                    help="comma-separated faults to plant (default: every fault of the cell)")
     ap.add_argument("--seconds", type=float, default=30.0)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
@@ -141,7 +151,11 @@ def main(argv=None) -> int:
         plan = [(s, None) for s in seeds] + [(s, f) for f in ("token_altered", "norm_gain_dropped")
                                              for s in control_seeds]
     else:
-        faults = ["unchanged", "half_batch", "norm_gain_dropped"]
+        import sample
+
+        faults = COUPLED_FAULTS if sample.coupled(cell["mix"]) else SAMPLE_FAULTS
+        if args.faults is not None:
+            faults = [f for f in args.faults.split(",") if f]
         plan = ([(s, None) for s in seeds] + [(s, "control") for s in control_seeds]
                 + [(s, f) for f in faults for s in control_seeds])
     mesh = Mesh(np.asarray(devices), ("chain",))

@@ -8,7 +8,13 @@ BENCH = Path(__file__).resolve().parents[1]
 # size on the chip; at this size the sound program's change over three steps
 # is more round-off than step (reads up to ~0.03), so the checks here hold
 # it to limits a tenth of what each planted fault reads here
-SAMPLE_LIMITS = {"loss_gap": 1e-3, "grad_gap": 0.1, "change_gap": 0.1}
+SAMPLE_LIMITS = {"loss_gap": 1e-3, "grad_gap": 0.1, "change_gap": 0.1, "exchange_gap": 0.1,
+                 "centre_gap": 0.1, "stray_collectives": 0, "missing_exchange": 0}
+# the coupled cell at this size (four CPU devices; seeds 5, 11, 2200000003
+# and 7300001701): the sound program's first gradient reads 1.7e-3-2.2e-3
+# and the float8 control's 1.4e-2-2.0e-2, so its limit here lies between
+# them, and the control put in the program's place comes out not correct
+COUPLED_GRAD_GAP = 6e-3
 # at this size the logits are nearly flat: sound runs read a widest gap of
 # 0-0.003 nats and an altered token 0.14-0.63, so the serving check here
 # holds the gap to its own limit, not the cell's
@@ -39,6 +45,11 @@ def sample_cell(traffic):
     cfg = _load("configs/qwen3-0.6b.posterior.json")
     cfg.update(SMALL)
     mix = _load(f"traffic/{traffic}.json")
-    mix.update(batch=4, seq=32, check={"limits": SAMPLE_LIMITS})
+    import sample
+
+    limits = {name: SAMPLE_LIMITS[name] for name in sample.checks_of(mix)}
+    if mix["sampler"] == "ec_sghmc":
+        limits["grad_gap"] = COUPLED_GRAD_GAP
+    mix.update(batch=4, seq=32, check=dict(mix["check"], limits=limits))
     return {"name": "sample.tiny", "chips": mix["chains"], "cfg": cfg, "mix": mix,
             "per_layer": [], "end_to_end": []}
